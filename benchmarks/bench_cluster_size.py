@@ -7,7 +7,7 @@ reproduces the same *shape* (optimum at small-moderate N, degradation at
 """
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import row, time_fn

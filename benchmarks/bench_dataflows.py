@@ -3,7 +3,7 @@ measured µs on 8 host devices + the analytical traffic crossover.
 """
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import row, time_fn

@@ -7,7 +7,7 @@ the fabric traffic from the paper's analytical model (§3.2).
 """
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from benchmarks.common import row, time_fn

@@ -78,7 +78,7 @@ def _unfused_decode_us(cfg, max_seq: int, batch: int, iters: int = 15):
     exist (all collectives degenerate to no-ops at size 1).
     """
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
 
     mesh1 = jax.make_mesh((1, 1), ("data", "model"))
     ctx = single_device_ctx()

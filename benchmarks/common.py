@@ -1,10 +1,10 @@
 """Benchmark utilities: timing + the 8-host-device subprocess pattern.
 
 All benchmarks print ``name,us_per_call,derived`` CSV rows (one per paper
-table/figure cell).  CPU wall-times are *relative* indicators (the roofline
-analysis in EXPERIMENTS.md carries the absolute performance story); the
-derived column carries the analytic quantity the paper's table reports
-(traffic bytes, speedup ratio, …).
+table/figure cell).  They run on the CPU: their wall times are relative
+indicators only and never a device metric (no number here was measured on
+a TPU); the derived column carries the analytic quantity the paper's table
+reports (traffic bytes, speedup ratio, …).
 """
 import time
 

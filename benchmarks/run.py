@@ -1,8 +1,9 @@
 """Benchmark harness: one function per paper table/figure.
 
-Prints ``name,us_per_call,derived`` CSV.  Benchmarks that need an 8-device
-mesh respawn themselves in a subprocess with the device-count flag (the
-main process keeps 1 device, per the assignment contract).
+Prints ``name,us_per_call,derived`` CSV.  CPU-only: each benchmark runs
+in a child process that forces 8 emulated host devices, while this parent
+never touches JAX; on a TPU host the children would each try to take the
+chip, so chip runs go through ``chip_smoke.py`` instead.
 """
 import os
 import subprocess

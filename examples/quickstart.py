@@ -12,7 +12,7 @@ if "xla_force_host_platform_device_count" not in os.environ.get(
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import primitives as prim

@@ -8,15 +8,19 @@ import argparse
 import os
 import time
 
-if "xla_force_host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""):
+# CPU runs (JAX_PLATFORMS=cpu) emulate 8 host devices; on a TPU the
+# mesh is built over the chips JAX finds
+if (os.environ.get("JAX_PLATFORMS") == "cpu"
+        and "xla_force_host_platform_device_count"
+        not in os.environ.get("XLA_FLAGS", "")):
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, reduced
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import device_mesh, make_test_mesh
+from repro.launch.runtime import enable_compile_cache, require_tpu
 from repro.launch.serve import build_engine, generate
 
 
@@ -36,8 +40,10 @@ def main():
                          "checkpoints always keep the training layout)")
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = reduced(get_config(args.arch))
-    mesh = make_test_mesh()
+    mesh = (make_test_mesh() if jax.default_backend() == "cpu"
+            else device_mesh(require_tpu()))
     key = jax.random.PRNGKey(0)
     prompts = jax.random.randint(key, (args.batch, 16), 0, cfg.vocab_size)
     fe = None

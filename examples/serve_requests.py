@@ -34,15 +34,19 @@ import argparse
 import os
 import time
 
-if "xla_force_host_platform_device_count" not in os.environ.get(
-        "XLA_FLAGS", ""):
+# CPU runs (JAX_PLATFORMS=cpu) emulate 8 host devices; on a TPU the
+# mesh is built over the chips JAX finds
+if (os.environ.get("JAX_PLATFORMS") == "cpu"
+        and "xla_force_host_platform_device_count"
+        not in os.environ.get("XLA_FLAGS", "")):
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, reduced
-from repro.launch.mesh import make_test_mesh
+from repro.launch.mesh import device_mesh, make_test_mesh
+from repro.launch.runtime import enable_compile_cache, require_tpu
 from repro.launch.serve import EngineOptions, build_engine_full
 from repro.serving.scheduler import Request, SlotScheduler, replay_trace
 
@@ -87,8 +91,10 @@ def main():
     if args.replicas > 1:
         return fleet_main(args)
 
+    enable_compile_cache()
     cfg = reduced(get_config(args.arch))
-    mesh = make_test_mesh(data=1, model=8)
+    mesh = (make_test_mesh(data=1, model=8)
+            if jax.default_backend() == "cpu" else device_mesh(require_tpu()))
     rng = np.random.default_rng(args.seed)
     max_new_cap = 12
     eng = build_engine_full(

@@ -1,7 +1,2 @@
-"""ClusterFusion reproduction package.
-
-Importing the package installs the JAX version-compat shims
-(:mod:`repro.compat`) so the rest of the codebase — and inline test
-bodies — can target one API surface regardless of the pinned JAX.
-"""
-from repro import compat  # noqa: F401  (side effect: compat.install())
+"""ClusterFusion reproduction package: a TPU decode-serving stack that
+fuses each decode layer into cluster-level Pallas kernels (DESIGN.md)."""

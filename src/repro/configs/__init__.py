@@ -2,7 +2,8 @@
 from repro.configs.base import (  # noqa: F401
     ATTN_GLOBAL, ATTN_LOCAL, RECURRENT, RWKV6,
     EncoderConfig, FrontendConfig, MLAConfig, MoEConfig, ModelConfig,
-    SHAPES, ShapeConfig, get_config, list_archs, reduced, register, shapes_for,
+    SHAPES, ShapeConfig, depth_cut, get_config, list_archs, reduced, register,
+    shapes_for,
 )
 
 # Assigned architectures (public pool) ------------------------------------

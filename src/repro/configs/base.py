@@ -8,6 +8,7 @@ dry-run, never allocated).
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Tuple
@@ -285,6 +286,18 @@ def list_archs() -> List[str]:
 # ---------------------------------------------------------------------------
 # Reduced configs for CPU smoke tests
 # ---------------------------------------------------------------------------
+def depth_cut(cfg: ModelConfig, n_layers: int) -> ModelConfig:
+    """``cfg`` at its published widths with only the depth cut to
+    ``n_layers`` — a whole number of block-pattern periods, so every layer
+    kind the model has is kept.  The name records the cut."""
+    period = len(cfg.block_pattern)
+    if n_layers <= 0 or n_layers % period or n_layers > cfg.n_layers:
+        raise ValueError(f"{cfg.name}: cannot cut {cfg.n_layers} layers to "
+                         f"{n_layers} (pattern period {period})")
+    return dataclasses.replace(cfg, name=f"{cfg.name}-L{n_layers}",
+                               n_layers=n_layers)
+
+
 def reduced(cfg: ModelConfig, *, d_model: int = 128, n_layers: int = 0,
             vocab: int = 512) -> ModelConfig:
     """Shrink a config to smoke-test size, preserving its structure.

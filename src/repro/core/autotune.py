@@ -14,7 +14,8 @@ latency/bandwidth vs active SMs; on TPU the analogous trade-off is:
 We pick N by minimizing an analytical per-token latency model built from
 the paper's traffic formulas plus v5e roofline constants.  This is the
 same *structure* as the paper's Appendix-B analysis, with DSMEM constants
-replaced by ICI/HBM constants.
+replaced by ICI/HBM constants from :data:`CHIP_PEAKS` — the figures of
+the rehearsed target, a TPU v5e (:data:`REHEARSED_KIND`).
 """
 from __future__ import annotations
 
@@ -28,10 +29,45 @@ from repro.configs.base import ATTN_GLOBAL, ATTN_LOCAL, ModelConfig
 from repro.core import dataflow as df
 from repro.core import primitives as prim
 
-# v5e hardware constants (per assignment)
-PEAK_FLOPS = 197e12          # bf16 FLOP/s per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks of one TPU generation."""
+    flops: float                 # bf16 FLOP/s
+    hbm_bw: float                # HBM bytes/s
+    ici_bw: float                # inter-chip bytes/s per link
+    source: str
+
+
+# Peaks keyed by ``jax.Device.device_kind``.  A kind that is not here is
+# an error (:func:`chip_peaks`), never a silent default.
+CHIP_PEAKS: Dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(
+        flops=197e12, hbm_bw=819e9,
+        ici_bw=50e9,             # 1,600 Gbit/s over 4 links
+        source='Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, '
+               '16 GB HBM at 819 GB/s, 1,600 Gbit/s inter-chip '
+               'interconnect'),
+}
+# The chip the CPU runs rehearse: tests/test_tpu_compile.py compiles for
+# a described v5e, and the analytical plans below use its figures.
+REHEARSED_KIND = "TPU v5 lite"
+
+
+def chip_peaks(kind: str) -> ChipPeaks:
+    """The :data:`CHIP_PEAKS` entry for a ``device_kind``; raises for a
+    kind without published figures here."""
+    try:
+        return CHIP_PEAKS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak figures for device kind {kind!r}; add them to "
+            f"CHIP_PEAKS with their source") from None
+
+
+_V5E = chip_peaks(REHEARSED_KIND)
+PEAK_FLOPS = _V5E.flops      # bf16 FLOP/s per chip
+HBM_BW = _V5E.hbm_bw         # bytes/s per chip
+ICI_BW = _V5E.ici_bw         # bytes/s per link
 ICI_LAT = 1e-6               # seconds per hop (round latency floor)
 GRID_STEP_OVH = 1e-6         # per-Pallas-grid-step fixed overhead (s)
 VMEM_BUDGET = 8 * 2**20      # bytes for double-buffered KV blocks

@@ -17,7 +17,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 
 def _kernel(cache_len_ref, q_ref, k_blk_ref, v_blk_ref,
@@ -115,7 +114,7 @@ def flash_decode_attention(
             ],
         ),
         out_shape=[jax.ShapeDtypeStruct((B, q_loc, hd), q.dtype)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(jnp.asarray(cache_len, jnp.int32).reshape(1), q, k_cache, v_cache)

@@ -48,7 +48,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import tracecount
-from repro.kernels import tpu_compiler_params
 from repro.models.layers import activation
 
 
@@ -176,7 +175,7 @@ def fused_ffn_block(
             jax.ShapeDtypeStruct((B, D), x.dtype),
             jax.ShapeDtypeStruct((B, D), x.dtype),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x, a, w_in, wg_op, w_out, ln2_op, post1_op, addr_op)

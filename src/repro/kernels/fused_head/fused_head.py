@@ -46,7 +46,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import tracecount
-from repro.kernels import tpu_compiler_params
 from repro.kernels.fused_head.topk import _INT32_MAX, select_topk
 
 
@@ -146,7 +145,7 @@ def fused_head_block(
             jax.ShapeDtypeStruct((B, k), jnp.float32),
             jax.ShapeDtypeStruct((B, k), jnp.int32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x, table, ln_op)

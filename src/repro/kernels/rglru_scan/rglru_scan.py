@@ -20,7 +20,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 
 def _kernel(log_a_ref, b_ref, h0_ref, out_ref, h_fin_ref, h_s,
@@ -75,7 +74,7 @@ def rglru_scan_kernel(log_a: jax.Array, b: jax.Array, h0: jax.Array,
             jax.ShapeDtypeStruct((B, C), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((B, blk_c), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(log_a, b, h0)

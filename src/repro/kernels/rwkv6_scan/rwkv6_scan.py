@@ -20,7 +20,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import tpu_compiler_params
 
 
 def _kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref,
@@ -88,7 +87,7 @@ def rwkv6_scan_kernel(r, k, v, w, u, s0, *, block_t: int = 64,
             jax.ShapeDtypeStruct((B, H, hd, hd), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((B, hb, hd, hd), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(r, k, v, w, u.reshape(1, H, hd), s0)

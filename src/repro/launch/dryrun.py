@@ -3,7 +3,8 @@ os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512")
 """Multi-pod dry-run: lower + compile every (architecture × input shape)
 cell on the production meshes, extract memory/cost/collective statistics,
-and emit the roofline terms (EXPERIMENTS.md §Dry-run / §Roofline).
+and emit the roofline terms against the v5e peaks of
+``core/autotune.CHIP_PEAKS``.
 
 MUST be imported before any other jax-touching module — the two lines
 above run before any other import so jax sees 512 host devices.
@@ -23,16 +24,12 @@ from typing import Dict, Optional
 import jax
 
 from repro.configs import SHAPES, get_config, list_archs, shapes_for
+from repro.core.autotune import HBM_BW, ICI_BW, PEAK_FLOPS  # noqa: F401
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import (build_decode_step, build_prefill_step,
                                 build_train_step)
 from repro.training.optimizer import OptConfig
 from repro.training.train_step import TrainConfig
-
-# v5e roofline constants (per chip)
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
 
 _COLL_RE = re.compile(
     r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"

@@ -6,6 +6,7 @@ touches jax device state (the dry-run sets XLA_FLAGS before first init).
 from __future__ import annotations
 
 import jax
+import numpy as np
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -13,6 +14,15 @@ def make_production_mesh(*, multi_pod: bool = False):
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
     return jax.make_mesh(shape, axes,
                          axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
+def device_mesh(devices=None):
+    """``(data=1, model=n)`` mesh over ``devices`` (default: all of
+    ``jax.devices()``), in the order given — the chip runs' mesh."""
+    devs = list(devices if devices is not None else jax.devices())
+    return jax.sharding.Mesh(
+        np.array(devs).reshape(1, len(devs)), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2)
 
 
 def make_test_mesh(data: int = 2, model: int = 4):

@@ -1,7 +1,11 @@
 """Serving driver: prefill a batch of prompts, then decode with the
-ClusterFusion dataflow.  Reduced configs run end-to-end on CPU
-(examples/serve_decode.py); full configs use the same code path on real
-hardware.
+ClusterFusion dataflow.
+
+On the CPU (``JAX_PLATFORMS=cpu`` with 8 host devices, Pallas in
+interpret mode) the CLI runs a ``reduced()`` smoke config.  On a TPU it
+runs over a ``(1, n)`` mesh of the chips JAX finds; ``--layers N``
+serves the published widths with the depth cut to N layers
+(``chip_smoke.py`` drives that path through the slot scheduler).
 
 Two serving modes share the engine:
 
@@ -22,12 +26,15 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.configs import get_config, reduced
+from repro.configs import depth_cut, get_config, reduced
 from repro.core.autotune import tune_serving
-from repro.launch.mesh import dp_axes_of, dp_size_of, make_test_mesh
+from repro.launch.mesh import (device_mesh, dp_axes_of, dp_size_of,
+                               make_test_mesh)
+from repro.launch.runtime import (check_interpret, enable_compile_cache,
+                                  require_tpu)
 from repro.launch.specs import _unwrap2, _wrap2, ctx_for, serving_layout
 from repro.configs.base import ShapeConfig
 from repro.models.transformer import init_device_major, param_specs
@@ -126,7 +133,8 @@ def build_engine_full(cfg, mesh, *, max_seq: int, batch_global: int,
     All construction knobs live on ONE object:
     ``options=EngineOptions(...)`` (serving/engine.py) — backend /
     interpret / block sizes / prepack / the state-leaf flags
-    (track_work, check_finite, kv_fingerprint, shadow_head) /
+    (track_work, check_finite, kv_fingerprint, shadow_head,
+    stash_candidates) /
     fused_combine / cluster / autotune_table / fuse_head /
     plan_seq_len.  The pre-options surface (the same names as
     individual keyword arguments) still works through a deprecation
@@ -134,7 +142,8 @@ def build_engine_full(cfg, mesh, *, max_seq: int, batch_global: int,
 
     ``options.backend``: "xla" | "pallas" | "auto" — local-stage compute
     for the decode dataflow (DESIGN.md §2); ``interpret`` runs the
-    Pallas kernels in interpret mode (CPU tests); ``block_s/f/v``
+    Pallas kernels in interpret mode (CPU only — it raises on a TPU
+    mesh); ``block_s/f/v``
     override the autotuned tiles; ``autotune_table`` persists plans
     across launches.
 
@@ -173,6 +182,7 @@ def build_engine_full(cfg, mesh, *, max_seq: int, batch_global: int,
     check_finite = opt.check_finite
     kv_fingerprint, shadow_head = opt.kv_fingerprint, opt.shadow_head
     plan_seq_len = opt.plan_seq_len
+    check_interpret(interpret, mesh)
     ms = mesh.shape["model"]
     dp_axes = dp_axes_of(mesh)
     dp = dp_size_of(mesh)
@@ -197,7 +207,8 @@ def build_engine_full(cfg, mesh, *, max_seq: int, batch_global: int,
                        prepack=plan.prepack, track_work=track_work,
                        check_finite=check_finite,
                        kv_fingerprint=kv_fingerprint,
-                       shadow_head=shadow_head)
+                       shadow_head=shadow_head,
+                       stash_candidates=opt.stash_candidates)
     params_abs = jax.eval_shape(
         lambda: init_device_major(cfg, lay, jax.random.PRNGKey(0)))
     p_specs = param_specs(cfg, params_abs)
@@ -374,9 +385,16 @@ def main():
                     choices=("auto", "on", "off"),
                     help="serve-layout weight prepack (auto: on whenever "
                          "the Pallas backend is selected)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="serve the published widths with the depth cut "
+                         "to this many layers (default: the reduced() "
+                         "smoke config)")
     args = ap.parse_args()
-    cfg = reduced(get_config(args.arch))
-    mesh = make_test_mesh()
+    enable_compile_cache()
+    cfg = get_config(args.arch)
+    cfg = depth_cut(cfg, args.layers) if args.layers else reduced(cfg)
+    on_cpu = jax.default_backend() == "cpu"
+    mesh = make_test_mesh() if on_cpu else device_mesh(require_tpu())
     params, pf, dec, state, lay, scfg = build_engine(
         cfg, mesh, max_seq=args.prompt_len + args.tokens + 8,
         batch_global=args.batch, backend=args.backend,

@@ -14,10 +14,11 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_config, reduced
+from repro.launch.runtime import enable_compile_cache
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import DataConfig, SyntheticLM, frontend_embeds_at
 from repro.launch.mesh import dp_axes_of, dp_size_of, make_test_mesh
@@ -186,6 +187,7 @@ def main():
                     help="full config (real hardware only)")
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
     run(args.arch, steps=args.steps, use_reduced=not args.full,
         ckpt_dir=args.ckpt)
 

@@ -67,7 +67,8 @@ class ServeConfig:
     # kernel backend for the per-layer local compute stage (DESIGN.md §2):
     # "xla" = block-bucketed XLA dataflow; "pallas" = fused decode kernels
     backend: str = "xla"
-    interpret: bool = False        # Pallas interpret mode (CPU/tests)
+    interpret: bool = False        # Pallas interpret mode (CPU only;
+                                   # raises on a TPU)
     block_s: int = 256             # KV block granularity (autotunable)
     block_f: int = 512             # d_ff tile of the fused-FFN megakernel
                                    # (autotunable; fitted to F_loc per call)
@@ -104,6 +105,10 @@ class ServeConfig:
     # re-derive the committed token's logit against a pristine head
     # copy.  Off by default.
     shadow_head: bool = False
+    # keep each decode step's merged head candidate values (the sorted
+    # k = CAND_K logits) in state["cand_v"] — chip_smoke.py compares them
+    # across backends.  Off by default.
+    stash_candidates: bool = False
 
 
 @dataclass(frozen=True)
@@ -115,7 +120,8 @@ class EngineOptions:
     Everything here is either resolved into the :class:`ServeConfig`
     the jitted steps close over (``backend`` / ``interpret`` /
     ``block_*`` / ``prepack`` / ``track_work`` / ``check_finite`` /
-    ``kv_fingerprint`` / ``shadow_head``) or consumed by the build
+    ``kv_fingerprint`` / ``shadow_head`` / ``stash_candidates``) or
+    consumed by the build
     itself (``fused_combine`` / ``cluster`` / ``autotune_table`` /
     ``fuse_head`` / ``plan_seq_len``).  ``None`` block sizes defer to
     the autotuned plan; ``plan_seq_len`` keys the autotune bucket on
@@ -135,6 +141,7 @@ class EngineOptions:
     check_finite: bool = False
     kv_fingerprint: bool = False
     shadow_head: bool = False
+    stash_candidates: bool = False
     plan_seq_len: Optional[int] = None
 
 
@@ -233,6 +240,8 @@ def init_decode_state(cfg: ModelConfig, scfg: ServeConfig, ctx: ParallelCtx
         state["head_resid"] = jnp.zeros((B, cfg.d_model), jnp.bfloat16)
         state["head_val"] = jnp.zeros((B,), jnp.float32)
         state["head_tok"] = jnp.zeros((B,), jnp.int32)
+    if scfg.stash_candidates:
+        state["cand_v"] = jnp.zeros((B, CAND_K), jnp.float32)
     if cfg.encoder is not None:
         kv_loc = max(1, cfg.n_kv_heads // hs)
         hd = cfg.resolved_head_dim
@@ -698,6 +707,8 @@ def decode_step(ctx: ParallelCtx, cfg: ModelConfig, scfg: ServeConfig,
         new_state["head_resid"] = x.astype(jnp.bfloat16)
         new_state["head_val"] = jnp.asarray(head_val, jnp.float32)
         new_state["head_tok"] = nxt.astype(jnp.int32)
+    if scfg.stash_candidates:
+        new_state["cand_v"] = cand_v.astype(jnp.float32)
     # only ACTIVE slots advance; free slots (−1) stay frozen until the
     # scheduler re-admits them via a prefill insert
     new_state["cache_lens"] = jnp.where(cache_len >= 0, cache_len + 1,

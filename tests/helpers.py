@@ -15,10 +15,9 @@ PRELUDE = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, {src!r})
-import repro  # installs repro.compat JAX version shims
 import jax, jax.numpy as jnp, numpy as np
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
 """
 
 

@@ -317,3 +317,72 @@ def test_rwkv6_scan_sweep(B, S, H, hd):
                                rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(sf), np.asarray(sf_r),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("fuse_out", ["partial_o", True, False])
+@pytest.mark.parametrize("B,dtype", [(1, jnp.float32), (2, jnp.bfloat16)])
+def test_fused_decode_streamed_weight_tiles(fuse_out, B, dtype):
+    """A tile budget of one head forces several projection and output
+    grid steps (the published-width layout, at a small size); the result
+    still matches the oracle."""
+    from repro.kernels.fused_decode.fused_decode import fused_decode_attention
+    from repro.kernels.fused_decode.ref import fused_decode_attention_ref
+    D, S, q_loc, kv_loc, hd, clen = 128, 256, 4, 2, 128, 150
+    ks = jax.random.split(jax.random.PRNGKey(21), 7)
+    P_ = (q_loc + 2 * kv_loc) * hd
+    x = (jax.random.normal(ks[0], (B, D)) * 0.2).astype(dtype)
+    wqkv = (jax.random.normal(ks[1], (D, P_)) * 0.05).astype(dtype)
+    bqkv = (jax.random.normal(ks[2], (P_,)) * 0.01).astype(dtype)
+    wo = jax.random.normal(ks[3], (q_loc, hd, D)) * 0.05
+    wo = (wo if fuse_out == "partial_o" else wo.reshape(q_loc * hd, D)
+          ).astype(dtype)
+    kc = (jax.random.normal(ks[4], (S, kv_loc, hd)) * 0.3).astype(dtype)
+    vc = (jax.random.normal(ks[5], (S, kv_loc, hd)) * 0.3).astype(dtype)
+    ln = jax.random.normal(ks[6], (D,)) * 0.1
+    cos, sin = rope_at(clen, hd)
+    args = (x, wqkv, bqkv, wo, kc, vc, clen, cos, sin)
+    kw = dict(q_heads=q_loc, kv_heads=kv_loc, fuse_out=fuse_out,
+              norm_scale=ln)
+    tile = D * hd * jnp.dtype(dtype).itemsize          # one head per tile
+    got = fused_decode_attention(*args, **kw, block_s=64, interpret=True,
+                                 weight_tile_bytes=tile)
+    want = fused_decode_attention_ref(*args, **kw)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g, np.float32),
+                                   np.asarray(w, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("fuse_out", ["partial_o", True, False])
+def test_fused_mla_streamed_weight_tiles(fuse_out):
+    """MLA with a one-head tile budget: several projection (wq + wuk) and
+    output (wuv / wo) grid steps still match the oracle."""
+    from repro.kernels.fused_mla_decode.fused_mla_decode import (
+        fused_mla_decode_attention)
+    from repro.kernels.fused_mla_decode.ref import (
+        fused_mla_decode_attention_ref)
+    B, D, S, q_loc = 2, 128, 256, 4
+    l_rank, rope_d, nope, v_dim = 128, 64, 64, 32
+    ks = jax.random.split(jax.random.PRNGKey(22), 8)
+    x = jax.random.normal(ks[0], (B, D)) * 0.2
+    wq = jax.random.normal(ks[1], (D, q_loc * (nope + rope_d))) * 0.05
+    wdkv = jax.random.normal(ks[2], (D, l_rank + rope_d)) * 0.05
+    wuk = jax.random.normal(ks[3], (q_loc, nope, l_rank)) * 0.05
+    wo = jax.random.normal(ks[5], (q_loc * v_dim, D)) * 0.05
+    wuv = jax.random.normal(ks[4], (q_loc, l_rank, v_dim)) * 0.05
+    if fuse_out == "partial_o":
+        wuv = jnp.einsum("qlv,qvd->qld", wuv, wo.reshape(q_loc, v_dim, D))
+    cc = jax.random.normal(ks[6], (S, l_rank + rope_d)) * 0.3
+    clen = 200
+    cos, sin = rope_at(clen, rope_d)
+    kw = dict(q_heads=q_loc, nope=nope, rope_d=rope_d, l_rank=l_rank,
+              v_dim=D if fuse_out == "partial_o" else v_dim,
+              fuse_out=fuse_out, norm_scale=jax.random.normal(ks[7], (D,)))
+    args = (x, wq, wdkv, wuk, wuv, wo, cc, clen, cos, sin)
+    got = fused_mla_decode_attention(*args, **kw, block_s=64, interpret=True,
+                                     weight_tile_bytes=2 * D * 4 * 64)
+    want = fused_mla_decode_attention_ref(*args, **kw)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=5e-5, atol=5e-5)

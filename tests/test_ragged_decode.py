@@ -51,7 +51,7 @@ def test_split_token_ragged_matches_per_sequence(backend, window, s_blk):
                           interpret=True, block_s=2)
     mesh = jax.make_mesh((1,), ("model",))
     from jax.sharding import PartitionSpec as P
-    from repro.compat import shard_map
+    from jax import shard_map
 
     def step(x, cache, cl):
         return df.split_token_attention(spec, x, w, cache, cl,
