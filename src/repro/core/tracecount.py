@@ -107,6 +107,29 @@ monitor probe call) and ``probe_bytes_kv`` / ``probe_bytes_weights`` /
 ``probe_bytes_shadow`` (host bytes pulled per probe family) — the
 bench's ``sdc_sweep.fault_free.probe_bytes_per_tick`` column divides
 these out, so per-tick probe overhead is a gated, tracked number.
+
+A fifth family, the SERVE-LOOP counters (:func:`record_serve`), is
+host-side and always on, like the two above; the slot scheduler and the
+router record (serving/scheduler.py, serving/router.py):
+
+* ``host_syncs`` / ``host_sync_bytes`` — every blocking device→host read
+  of the serve loop (``scheduler.fetch``) and the bytes it brought back:
+  per tick the router probe's ``cache_lens``, per decode the next tokens
+  and the integrity latch's ``cache_lens``, per admit the first tokens
+  (and the ``nonfinite`` leaf beside each ``cache_lens`` where the step
+  has one).
+* ``admit_rows`` — real prompt tokens admitted.
+* ``admit_positions`` — positions the padded admit ran:
+  ``slots × prompt_cap`` per admit call.  ``1 − admit_rows /
+  admit_positions`` is the share of the admit that is padding.
+
+Decode calls and ticks are not counted here: ``SlotScheduler`` and
+``Router`` keep those (``decode_calls``, ``tick``).  The same loop opens
+``jax.profiler.TraceAnnotation`` spans named ``serve.*`` (``serve.tick``
+and its children: ``serve.dispatch``, ``serve.admit`` /
+``serve.admit.wait``, ``serve.decode`` / ``serve.decode.wait``,
+``serve.retire``, ``serve.latch``, ``serve.probe``, ``serve.commit``);
+they cost about a microsecond each when no profiler trace is active.
 """
 from __future__ import annotations
 
@@ -188,6 +211,25 @@ def probe_totals() -> Counter:
 def reset_probes() -> None:
     """Zero the probe-overhead counters (test / bench isolation)."""
     _PROBES.clear()
+
+
+_SERVE: Counter = Counter()
+
+
+def record_serve(name: str, n: int = 1) -> None:
+    """Count serve-loop work (always on, host-side — see the serve-loop
+    label list in the module docstring)."""
+    _SERVE[name] += n
+
+
+def serve_totals() -> Counter:
+    """Snapshot of the serve-loop counters."""
+    return Counter(_SERVE)
+
+
+def reset_serve() -> None:
+    """Zero the serve-loop counters (test isolation)."""
+    _SERVE.clear()
 
 
 @contextmanager

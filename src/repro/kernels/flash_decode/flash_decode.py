@@ -117,5 +117,6 @@ def flash_decode_attention(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="flash_decode",
     )(jnp.asarray(cache_len, jnp.int32).reshape(1), q, k_cache, v_cache)
     return o

@@ -547,6 +547,7 @@ def fused_decode_attention(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="fused_decode",
     )(scalars,
       x, wqkv, bqkv.reshape(1, -1), wo,
       cos.reshape(1, -1), sin.reshape(1, -1), norm_op,

@@ -178,5 +178,6 @@ def fused_ffn_block(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="fused_ffn",
     )(x, a, w_in, wg_op, w_out, ln2_op, post1_op, addr_op)
     return tuple(out)

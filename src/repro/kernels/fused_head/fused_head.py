@@ -148,5 +148,6 @@ def fused_head_block(
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="fused_head",
     )(x, table, ln_op)
     return out[0], out[1]

@@ -385,6 +385,7 @@ def fused_mla_decode_attention(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
+        name="fused_mla_decode",
     )(scalars,
       x, wq, wdkv, wuk, wuv, wo, cos.reshape(1, -1), sin.reshape(1, -1),
       norm_op, c_cache, jnp.asarray(pos, jnp.int32).reshape(1, S))

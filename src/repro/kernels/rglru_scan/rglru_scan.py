@@ -77,5 +77,6 @@ def rglru_scan_kernel(log_a: jax.Array, b: jax.Array, h0: jax.Array,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="rglru_scan",
     )(log_a, b, h0)
     return out, h_fin

@@ -90,5 +90,6 @@ def rwkv6_scan_kernel(r, k, v, w, u, s0, *, block_t: int = 64,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="rwkv6_scan",
     )(r, k, v, w, u.reshape(1, H, hd), s0)
     return o, s_fin

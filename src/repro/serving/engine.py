@@ -602,9 +602,13 @@ def decode_step(ctx: ParallelCtx, cfg: ModelConfig, scfg: ServeConfig,
             block_s=df._fit_block_s(s_blk, scfg.block_s),
             rank=ctx.cluster_index(), window=window, ring=window > 0)
 
-    x = embed_lookup(ctx, EmbedParams(params["embed"]), tokens)
-    if cfg.tie_embeddings:
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+    # named scopes (embed, layers, kv_read, kv_write, head) reach the
+    # compiled ops' metadata, so a profiler trace can tell the XLA glue
+    # around the named Pallas kernels apart
+    with jax.named_scope("embed"):
+        x = embed_lookup(ctx, EmbedParams(params["embed"]), tokens)
+        if cfg.tie_embeddings:
+            x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
 
     if cfg.encoder is not None:
         enc_kv_all = state["enc_kv"]
@@ -625,7 +629,8 @@ def decode_step(ctx: ParallelCtx, cfg: ModelConfig, scfg: ServeConfig,
             ca = ek = ev = None
         new_caches = []
         for p_i in range(period):
-            cache_i = jax.tree.map(lambda l: l[gi], caches[p_i])
+            with jax.named_scope("kv_read"):
+                cache_i = jax.tree.map(lambda l: l[gi], caches[p_i])
             blk = blks[p_i]
             enc = None
             if ca is not None:
@@ -635,17 +640,19 @@ def decode_step(ctx: ParallelCtx, cfg: ModelConfig, scfg: ServeConfig,
             work = work + _blk_work(kinds[p_i], cache_i)
             x, nc = decode_block(ctx, cfg, kinds[p_i], blk, x,
                                  cache_i, cache_len, scfg, enc)
-            new_caches.append(jax.tree.map(
-                lambda full, upd: lax.dynamic_update_index_in_dim(
-                    full, upd.astype(full.dtype), gi, axis=0),
-                caches[p_i], nc))
+            with jax.named_scope("kv_write"):
+                new_caches.append(jax.tree.map(
+                    lambda full, upd: lax.dynamic_update_index_in_dim(
+                        full, upd.astype(full.dtype), gi, axis=0),
+                    caches[p_i], nc))
         return (x, tuple(new_caches), work), None
 
     xs = ((tuple(params["blocks"]), n_groups_t, params["cross_attn"],
            enc_kv_all["k"], enc_kv_all["v"]) if cfg.encoder is not None
           else (tuple(params["blocks"]), n_groups_t))
-    (x, new_caches, work), _ = lax.scan(
-        group_body, (x, tuple(state["layers"]), work0), xs)
+    with jax.named_scope("layers"):
+        (x, new_caches, work), _ = lax.scan(
+            group_body, (x, tuple(state["layers"]), work0), xs)
 
     new_state = dict(state)
     new_state["layers"] = list(new_caches)
@@ -668,19 +675,20 @@ def decode_step(ctx: ParallelCtx, cfg: ModelConfig, scfg: ServeConfig,
     # top-p / PRNG finalize on the merged candidates, params riding
     # state["sampling"] (serving/sampling.py; greedy default = bit-
     # identical to the PR-5 (max, argmax) pair).
-    samp = state["sampling"]
-    head = params.get("head")
-    if isinstance(head, df.PackedHeadWeights):
-        cand_v, cand_i = _fused_head_tail(ctx, cfg, scfg, head, x)
-    else:
-        xh = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        table = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-        logits = lm_head_logits(ctx, table, xh)
-        if cfg.logit_softcap:
-            logits = softcap(logits, cfg.logit_softcap)
-        cand_v, cand_i = head_candidates(ctx, logits)
-    nxt, head_val = finalize_candidates(cand_v, cand_i, samp)
-    new_state["sampling"] = advance_sampling_step(samp, cache_len >= 0)
+    with jax.named_scope("head"):
+        samp = state["sampling"]
+        head = params.get("head")
+        if isinstance(head, df.PackedHeadWeights):
+            cand_v, cand_i = _fused_head_tail(ctx, cfg, scfg, head, x)
+        else:
+            xh = rms_norm(x, params["final_norm"], cfg.norm_eps)
+            table = params["embed"] if cfg.tie_embeddings else params["lm_head"]
+            logits = lm_head_logits(ctx, table, xh)
+            if cfg.logit_softcap:
+                logits = softcap(logits, cfg.logit_softcap)
+            cand_v, cand_i = head_candidates(ctx, logits)
+        nxt, head_val = finalize_candidates(cand_v, cand_i, samp)
+        new_state["sampling"] = advance_sampling_step(samp, cache_len >= 0)
     if scfg.check_finite:
         new_state["nonfinite"] = state["nonfinite"] + _finite_violations(
             cfg, x, head_val, nxt, cache_len >= 0)

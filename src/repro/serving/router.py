@@ -67,8 +67,8 @@ from __future__ import annotations
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
-import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import tracecount
 from repro.launch.serve import EngineHandle
@@ -76,7 +76,8 @@ from repro.serving.faults import ReplicaKilled
 from repro.serving.integrity import IntegrityConfig, IntegrityMonitor
 from repro.serving.sampling import (GREEDY, SamplingParams,
                                     validate_sampling)
-from repro.serving.scheduler import Request, SchedulerHooks, SlotScheduler
+from repro.serving.scheduler import (Request, SchedulerHooks, SlotScheduler,
+                                     fetch)
 
 
 @dataclass
@@ -164,10 +165,9 @@ class _Replica:
         st = self.sched.state
         n = self.sched.n_slots
         if "nonfinite" in st:
-            nf = np.asarray(jax.device_get(st["nonfinite"])).reshape(-1, n)
-            if (nf > 0).any():
+            if (fetch(st["nonfinite"]) > 0).any():
                 fired.append("detect_nonfinite")
-        lens = np.asarray(jax.device_get(st["cache_lens"])).reshape(-1, n)
+        lens = fetch(st["cache_lens"]).reshape(-1, n)
         if ((lens < -1).any() or
                 (lens > self.eng.scfg.max_seq).any() or
                 (lens != lens[0]).any()):      # shard disagreement
@@ -397,27 +397,31 @@ class Router:
 
     # -- one fleet tick ---------------------------------------------------
     def step(self, arrivals: Sequence[Request] = ()) -> None:
-        for req in arrivals:
-            self.submit(req)
-        self._heal_pending()     # last tick's quarantines rejoin first
-        self._dispatch()
-        for r in self.replicas:
-            if not r.alive:
-                continue
-            try:
-                r.sched.step()
-            except ReplicaKilled:
-                self._fail(r, ["detect_heartbeat"])
-                continue
-            signals = r.probe()
-            if signals:
-                self._fail(r, signals)
-            else:
-                self._stage(r)
-                self._commit(r)
-        self.live_frac.append(
-            sum(r.alive for r in self.replicas) / len(self.replicas))
-        self.tick += 1
+        with TraceAnnotation("serve.tick", tick=self.tick):
+            with TraceAnnotation("serve.dispatch"):
+                for req in arrivals:
+                    self.submit(req)
+                self._heal_pending()   # last tick's quarantines rejoin first
+                self._dispatch()
+            for r in self.replicas:
+                if not r.alive:
+                    continue
+                try:
+                    r.sched.step()
+                except ReplicaKilled:
+                    self._fail(r, ["detect_heartbeat"])
+                    continue
+                with TraceAnnotation("serve.probe"):
+                    signals = r.probe()
+                if signals:
+                    self._fail(r, signals)
+                else:
+                    with TraceAnnotation("serve.commit"):
+                        self._stage(r)
+                        self._commit(r)
+            self.live_frac.append(
+                sum(r.alive for r in self.replicas) / len(self.replicas))
+            self.tick += 1
 
     def idle(self) -> bool:
         return (not self.pending and not self._to_heal

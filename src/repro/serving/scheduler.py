@@ -35,11 +35,23 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
+from repro.core import tracecount
 from repro.launch.serve import EngineHandle
 from repro.serving.sampling import (GREEDY, SamplingParams,
                                     fill_sampling_row, host_sampling_rows,
                                     validate_sampling)
+
+
+def fetch(x) -> np.ndarray:
+    """Blocking device→host read of ``x``.  Every such read of the serve
+    loop (scheduler and router) goes through here, so each is counted:
+    ``host_syncs`` and ``host_sync_bytes`` (core/tracecount.py)."""
+    a = np.asarray(jax.device_get(x))
+    tracecount.record_serve("host_syncs")
+    tracecount.record_serve("host_sync_bytes", a.nbytes)
+    return a
 
 
 @dataclass
@@ -186,8 +198,7 @@ class SlotScheduler:
     # -- host views of the device state ----------------------------------
     def cache_lens(self) -> np.ndarray:
         """Per-slot cache lengths (−1 = free); identical across shards."""
-        leaf = np.asarray(jax.device_get(self.state["cache_lens"]))
-        return leaf.reshape(-1, self.n_slots)[0]
+        return fetch(self.state["cache_lens"]).reshape(-1, self.n_slots)[0]
 
     def work_blocks(self) -> np.ndarray:
         """Per-slot attend-step counters, summed over the (dp, model)
@@ -195,8 +206,8 @@ class SlotScheduler:
         (core/tracecount.live_attend_blocks)."""
         if "work_blocks" not in self.state:
             raise ValueError("build the engine with track_work=True")
-        leaf = np.asarray(jax.device_get(self.state["work_blocks"]))
-        return leaf.reshape(-1, self.n_slots).sum(axis=0)
+        return fetch(self.state["work_blocks"]).reshape(
+            -1, self.n_slots).sum(axis=0)
 
     # -- host model of the device cache lengths ---------------------------
     def expected_cache_lens(self) -> np.ndarray:
@@ -226,19 +237,18 @@ class SlotScheduler:
         """Snapshot per-slot integrity violations BEFORE the post-decode
         retire can reset them (see ``integrity_latch``).  All reads are
         [shards, B] host pulls — the same cost as one router probe."""
-        st = self.state
-        if "nonfinite" in st:
-            nf = np.asarray(jax.device_get(st["nonfinite"]))
-            if (nf > 0).any():
-                self.latched.append("detect_nonfinite")
-        lens = np.asarray(jax.device_get(st["cache_lens"]))
-        lens = lens.reshape(-1, self.n_slots)
-        if ((lens < -1).any()
-                or (lens > self.eng.scfg.max_seq).any()
-                or (lens != lens[0]).any()):
-            self.latched.append("detect_lens_bounds")
-        if (lens[0] != self.expected_cache_lens()).any():
-            self.latched.append("detect_journal_stale")
+        with TraceAnnotation("serve.latch"):
+            st = self.state
+            if "nonfinite" in st:
+                if (fetch(st["nonfinite"]) > 0).any():
+                    self.latched.append("detect_nonfinite")
+            lens = fetch(st["cache_lens"]).reshape(-1, self.n_slots)
+            if ((lens < -1).any()
+                    or (lens > self.eng.scfg.max_seq).any()
+                    or (lens != lens[0]).any()):
+                self.latched.append("detect_lens_bounds")
+            if (lens[0] != self.expected_cache_lens()).any():
+                self.latched.append("detect_journal_stale")
 
     # -- request intake ---------------------------------------------------
     def submit(self, req: Request) -> None:
@@ -282,28 +292,33 @@ class SlotScheduler:
             admitted.append((free.pop(0), self.queue.pop(0)))
         if not admitted:
             return
-        toks = np.zeros((self.n_slots, self.prompt_cap), np.int32)
-        lens = np.zeros((self.n_slots,), np.int32)
-        samp = host_sampling_rows(self.n_slots)
-        for b, req in admitted:
-            toks[b, :len(req.prompt)] = np.asarray(req.prompt, np.int32)
-            lens[b] = len(req.prompt)
-            fill_sampling_row(samp, b, req.sampling)
-        if self.hooks is not None:
-            toks, lens = self.hooks.admit_args(self, toks, lens)
-        first, self.state = self.eng.admit_fn(
-            self.eng.params["train"], self.state, toks, lens, samp)
-        first = np.asarray(jax.device_get(first)).reshape(-1)
-        for b, req in admitted:
-            self.slots[b] = _Slot(rid=req.rid, remaining=req.max_new,
-                                  prompt_len=len(req.prompt),
-                                  replay=list(req.replay))
-            res = self.results[req.rid]
-            res.slot, res.admit_tick = b, self.tick
-            self.events.append((self.tick, "admit", req.rid, b))
-            self._emit(b, int(first[b]))
-        if self.hooks is not None:
-            self.hooks.post_admit(self)
+        with TraceAnnotation("serve.admit", tick=self.tick,
+                             rids=[req.rid for _, req in admitted]):
+            toks = np.zeros((self.n_slots, self.prompt_cap), np.int32)
+            lens = np.zeros((self.n_slots,), np.int32)
+            samp = host_sampling_rows(self.n_slots)
+            for b, req in admitted:
+                toks[b, :len(req.prompt)] = np.asarray(req.prompt, np.int32)
+                lens[b] = len(req.prompt)
+                fill_sampling_row(samp, b, req.sampling)
+            tracecount.record_serve("admit_rows", int(lens.sum()))
+            tracecount.record_serve("admit_positions", toks.size)
+            if self.hooks is not None:
+                toks, lens = self.hooks.admit_args(self, toks, lens)
+            first, self.state = self.eng.admit_fn(
+                self.eng.params["train"], self.state, toks, lens, samp)
+            with TraceAnnotation("serve.admit.wait"):
+                first = fetch(first).reshape(-1)
+            for b, req in admitted:
+                self.slots[b] = _Slot(rid=req.rid, remaining=req.max_new,
+                                      prompt_len=len(req.prompt),
+                                      replay=list(req.replay))
+                res = self.results[req.rid]
+                res.slot, res.admit_tick = b, self.tick
+                self.events.append((self.tick, "admit", req.rid, b))
+                self._emit(b, int(first[b]))
+            if self.hooks is not None:
+                self.hooks.post_admit(self)
 
     def _emit(self, b: int, tok: int) -> None:
         s = self.slots[b]
@@ -328,15 +343,16 @@ class SlotScheduler:
                         and s.last_tok == self.eos_id))]
         if not fin:
             return
-        mask = np.zeros((self.n_slots,), np.int32)
-        for b in fin:
-            mask[b] = 1
-            rid = self.slots[b].rid
-            self.results[rid].finish_tick = self.tick
-            self.events.append((self.tick, "finish", rid, b))
-            self._replay_mismatch_retired += self.slots[b].replay_mismatch
-            self.slots[b] = _Slot()
-        self.state = self.eng.retire_fn(self.state, mask)
+        with TraceAnnotation("serve.retire"):
+            mask = np.zeros((self.n_slots,), np.int32)
+            for b in fin:
+                mask[b] = 1
+                rid = self.slots[b].rid
+                self.results[rid].finish_tick = self.tick
+                self.events.append((self.tick, "finish", rid, b))
+                self._replay_mismatch_retired += self.slots[b].replay_mismatch
+                self.slots[b] = _Slot()
+            self.state = self.eng.retire_fn(self.state, mask)
 
     # -- one scheduler tick ----------------------------------------------
     def step(self) -> None:
@@ -367,9 +383,12 @@ class SlotScheduler:
                 if self.hooks is not None:
                     params, st, ti = self.hooks.decode_args(
                         self, params, st, ti)
-                nxt, self.state = self.eng.decode_fn(params, st, ti)
-                self.decode_calls += 1
-                nxt = np.asarray(jax.device_get(nxt)).reshape(-1)
+                with TraceAnnotation("serve.decode", tick=self.tick,
+                                     step=self.decode_calls):
+                    nxt, self.state = self.eng.decode_fn(params, st, ti)
+                    self.decode_calls += 1
+                    with TraceAnnotation("serve.decode.wait"):
+                        nxt = fetch(nxt).reshape(-1)
             for b in active:
                 self._emit(b, int(nxt[b]))
             if self.integrity_latch:

@@ -7,13 +7,16 @@ tiles).  These tests lower each kernel exactly as the serving engine
 calls it — one batch-1 kernel per slot, ``vmap``-ed over 8 slots with
 the scalar-prefetch operands batched (``core/dataflow.py``) — and
 compile it for one chip of a ``v5e:2x2`` topology.  Nothing runs: a
-compile that passes says nothing about results or times.
+compile that passes says nothing about results or times.  Each kernel's
+``name=`` must survive into the compiled module as the name of its
+custom call: a profiler trace reads per-kernel time by that name.
 
 The topology is described inside a module fixture, never at import:
 only one process may load the TPU library, and pytest-xdist workers all
 import this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -67,8 +70,20 @@ def compile_for(one_chip):
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _assert_kernel(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+def _assert_kernel(compiled, name):
+    """The compiled module runs the Pallas kernel as a TPU custom call
+    that carries the kernel's name: as the instruction's own name
+    (``%<name>.<n> = ...``), or, where ``vmap`` turned the per-slot
+    kernel into a loop of calls, in its ``op_name`` metadata
+    (``.../vmap(<name>)/while/body/closed_call``)."""
+    calls = [ln for ln in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in ln]
+    assert calls
+    for ln in calls:
+        inst = re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ", ln)
+        path = re.search(r'op_name="([^"]*)"', ln).group(1).split("/")
+        assert (inst.group(1) == name or name in path
+                or f"vmap({name})" in path), (inst.group(0), path)
 
 
 @pytest.mark.parametrize("mode", ["partial_o", "partial_o_ring", "adapter"])
@@ -98,7 +113,7 @@ def test_fused_decode_compiles_granite(compile_for, mode):
         ((SLOTS,), jnp.int32), ((SLOTS, hd // 2), jnp.float32),
         ((SLOTS, hd // 2), jnp.float32), ((MAX_SEQ, SLOTS), jnp.int32),
         ((SLOTS,), jnp.int32), ((D,), jnp.float32))
-    _assert_kernel(c)
+    _assert_kernel(c, "fused_decode")
 
 
 def test_fused_ffn_compiles_granite(compile_for):
@@ -113,7 +128,7 @@ def test_fused_ffn_compiles_granite(compile_for):
     c = compile_for(step, ((SLOTS, D), BF16), ((SLOTS, D), BF16),
                     ((D, F), BF16), ((D, F), BF16), ((F, D), BF16),
                     ((D,), BF16))
-    _assert_kernel(c)
+    _assert_kernel(c, "fused_ffn")
 
 
 def test_fused_head_compiles_granite(compile_for):
@@ -128,7 +143,7 @@ def test_fused_head_compiles_granite(compile_for):
         return fused_head_block(x, table, ln, block_v=bv, k=CAND_K)
 
     c = compile_for(step, ((SLOTS, D), BF16), ((V, D), BF16), ((D,), BF16))
-    _assert_kernel(c)
+    _assert_kernel(c, "fused_head")
 
 
 def test_fused_mla_decode_compiles_deepseek_v2_lite(compile_for):
@@ -159,4 +174,4 @@ def test_fused_mla_decode_compiles_deepseek_v2_lite(compile_for):
         ((SLOTS, m.rope_head_dim // 2), jnp.float32),
         ((MAX_SEQ, SLOTS), jnp.int32), ((SLOTS,), jnp.int32),
         ((D,), jnp.float32))
-    _assert_kernel(c)
+    _assert_kernel(c, "fused_mla_decode")
