@@ -413,7 +413,9 @@ class PackedFFNWeights(NamedTuple):
     ``w_in``  [D, F_loc] up columns · ``w_gate`` [D, F_loc] or None ·
     ``w_out`` [F_loc, D] full-width down rows · ``ln2`` [D] pre-FFN
     norm scale · ``post_ln1`` [D] post-attention norm scale (Gemma-2
-    sandwich) or None.
+    sandwich) or None · ``layer`` int32 index into ``[L, …]`` stacks of
+    the three weights, read in place by the kernel (decode scan), or
+    None for one layer's weights.
     """
 
     w_in: jax.Array
@@ -421,6 +423,7 @@ class PackedFFNWeights(NamedTuple):
     ln2: jax.Array
     w_gate: Optional[jax.Array] = None
     post_ln1: Optional[jax.Array] = None
+    layer: Optional[jax.Array] = None
 
 
 class PackedHeadWeights(NamedTuple):

@@ -18,6 +18,14 @@ Labels used across the codebase:
   the train-layout adapters (``_split_token_weights``/``_mla_weights``).
 * ``weight_slice_hoisted`` — the once-per-step rank slices hoisted out
   of the layer scan (non-prepacked fast path).
+* ``weights_by_index`` — ``fused_ffn`` calls traced with ``[L, …]``
+  weight stacks and a layer index: the kernel reads the layer's tiles in
+  place, so the decode scan writes no per-layer copy of the FFN weights.
+  On the prepacked Pallas path one per scanned dense-FFN layer position;
+  zero for the unstacked tail and for any caller passing one layer's
+  weights.  Not the same as ``weight_slice``, which counts rank slicing
+  and read zero on the prepacked path even while the scan still copied
+  each layer.
 * ``pallas_kernel`` — ``pallas_call`` launch counter: one bump per
   kernel invocation as it traces (a vmapped kernel traces once, so this
   is the per-step launch count under ``jit``).

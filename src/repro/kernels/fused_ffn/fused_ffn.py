@@ -36,6 +36,14 @@ applies ``r + rms(reduce(partial), post_ln2)`` after the combine.
 Ragged decode needs no gating here: the FFN is position-independent and
 slot-local, so free slots simply flow through (their output is ignored
 by the scheduler), exactly as on the XLA path.
+
+**Stacked weights.**  The decode scan keeps every layer's weights in one
+``[L, …]`` stack.  Handed the stacks (3-D ``w_in``/``w_gate``/``w_out``)
+and an int32 ``layer``, the kernel reads that layer's tiles in place:
+``layer`` rides a scalar-prefetch operand and leads the weight index
+maps, over a squeezed leading block dim.  Without it XLA would copy the
+layer's slice into a fresh buffer before every call (a Pallas operand
+cannot be a strided view).  2-D weights take the plain grid.
 """
 from __future__ import annotations
 
@@ -101,10 +109,17 @@ def _kernel(x_ref, a_ref, wi_ref, wg_ref, wo_ref, ln2_ref, post1_ref,
         r_ref[...] = r_s[...].astype(r_ref.dtype)
 
 
+def _skip_layer(body, layer_ref, *refs):
+    """The kernel body on a stack: the layer scalar only steers the
+    weight index maps."""
+    body(*refs)
+
+
 def fused_ffn_block(
     x: jax.Array,                     # [B, D] raw residual stream
     a: jax.Array,                     # [B, D] attention output (pre-residual)
     w_in: jax.Array,                  # [D, F_loc] up-projection columns
+                                      # (or an [L, D, F_loc] stack)
     w_gate: Optional[jax.Array],      # [D, F_loc] gate columns, or None
     w_out: jax.Array,                 # [F_loc, D] FULL-width down rows
     ln2: jax.Array,                   # [D] pre-FFN RMSNorm scale
@@ -116,6 +131,7 @@ def fused_ffn_block(
     eps: float = 1e-6,
     block_f: int = 512,
     interpret: bool = False,
+    layer: Optional[jax.Array] = None,  # int32 layer of [L, …] weight stacks
 ) -> Tuple[jax.Array, jax.Array]:
     """Returns ``(o, r)``.
 
@@ -123,11 +139,18 @@ def fused_ffn_block(
     in ``x.dtype`` — ClusterReduce over the model axis completes the
     layer.  ``r [B, D]``: the post-first-residual stream (needed only by
     ``post_ln2`` callers, which apply the second residual add outside).
+
+    With 3-D weights (``[L, D, F_loc]`` / ``[L, F_loc, D]`` stacks) the
+    kernel reads layer ``layer`` of each stack in place.
     """
     tracecount.bump("pallas_kernel")
     tracecount.bump("ffn_pallas_kernel")
+    stacked = w_in.ndim == 3
+    if stacked:
+        assert layer is not None, "stacked FFN weights need a layer index"
+        tracecount.bump("weights_by_index")
     B, D = x.shape
-    F_loc = w_in.shape[1]
+    F_loc = w_in.shape[-1]
     bf = min(block_f, F_loc)
     assert F_loc % bf == 0, (F_loc, bf)
     n_f = F_loc // bf
@@ -146,31 +169,52 @@ def fused_ffn_block(
     def col_tile(j):
         return (0, j)
 
-    wg_spec = (pl.BlockSpec((D, bf), col_tile) if gated
-               else pl.BlockSpec((1, 1), lambda j: (0, 0)))
+    def weight_spec(block, index):
+        """A weight tile; on a stack the squeezed leading dim is read at
+        the prefetched layer scalar."""
+        if not stacked:
+            return pl.BlockSpec(block, index)
+        return pl.BlockSpec((None,) + block,
+                            lambda j, s: (s[0],) + index(j))
 
-    out = pl.pallas_call(
-        kernel,
-        grid=(n_f,),
+    def const(j, *_):
+        return (0, 0)
+
+    wg_spec = (weight_spec((D, bf), col_tile) if gated
+               else pl.BlockSpec((1, 1), const))
+    specs = dict(
         in_specs=[
-            pl.BlockSpec((B, D), lambda j: (0, 0)),            # x
-            pl.BlockSpec((B, D), lambda j: (0, 0)),            # a
-            pl.BlockSpec((D, bf), col_tile),                   # w_in tile
+            pl.BlockSpec((B, D), const),                       # x
+            pl.BlockSpec((B, D), const),                       # a
+            weight_spec((D, bf), col_tile),                    # w_in tile
             wg_spec,                                           # w_gate tile
-            pl.BlockSpec((bf, D), lambda j: (j, 0)),           # w_out rows
-            pl.BlockSpec(ln2_op.shape, lambda j: (0, 0)),      # ln2
-            pl.BlockSpec(post1_op.shape, lambda j: (0, 0)),    # post_ln1
-            pl.BlockSpec((1, 1), lambda j: (0, 0)),            # add_r
+            weight_spec((bf, D), lambda j: (j, 0)),            # w_out rows
+            pl.BlockSpec(ln2_op.shape, const),                 # ln2
+            pl.BlockSpec(post1_op.shape, const),               # post_ln1
+            pl.BlockSpec((1, 1), const),                       # add_r
         ],
         out_specs=[
-            pl.BlockSpec((B, D), lambda j: (0, 0)),
-            pl.BlockSpec((B, D), lambda j: (0, 0)),
+            pl.BlockSpec((B, D), const),
+            pl.BlockSpec((B, D), const),
         ],
         scratch_shapes=[
             pltpu.VMEM((B, D), jnp.float32),                   # r
             pltpu.VMEM((B, D), jnp.float32),                   # h (normed)
             pltpu.VMEM((B, D), jnp.float32),                   # accumulator
         ],
+    )
+    if stacked:
+        grid = dict(grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(n_f,), **specs))
+        lead = (jnp.asarray(layer, jnp.int32).reshape(1),)
+        kernel = functools.partial(_skip_layer, kernel)
+    else:
+        grid = dict(grid=(n_f,), **specs)
+        lead = ()
+
+    out = pl.pallas_call(
+        kernel,
+        **grid,
         out_shape=[
             jax.ShapeDtypeStruct((B, D), x.dtype),
             jax.ShapeDtypeStruct((B, D), x.dtype),
@@ -179,5 +223,5 @@ def fused_ffn_block(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
         name="fused_ffn",
-    )(x, a, w_in, wg_op, w_out, ln2_op, post1_op, addr_op)
+    )(*lead, x, a, w_in, wg_op, w_out, ln2_op, post1_op, addr_op)
     return tuple(out)

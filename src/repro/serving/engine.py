@@ -369,7 +369,8 @@ def _fused_ffn_tail(ctx: ParallelCtx, cfg: ModelConfig, scfg: ServeConfig,
     bf = df._fit_block_s(w.w_in.shape[-1], scfg.block_f)
     o_part, r = fused_ffn_block(
         x, a, w.w_in, w.w_gate, w.w_out, w.ln2, w.post_ln1, add_r,
-        act=cfg.ffn_act, eps=eps, block_f=bf, interpret=scfg.interpret)
+        act=cfg.ffn_act, eps=eps, block_f=bf, interpret=scfg.interpret,
+        layer=w.layer)
     n = ctx.model_size
     if ctx.model is None:
         f = o_part
@@ -561,6 +562,38 @@ def _check_not_param_pair(params_dm: PyTree, want: str) -> None:
             "generate() and the bench_tpot.py call sites)")
 
 
+_FFN_STACKED = ("w_in", "w_gate", "w_out")
+
+
+def _split_weight_stacks(blk: Dict[str, Any]):
+    """``(per-layer part, weight stacks)`` of one scanned block.
+
+    A Pallas operand cannot be a strided view, so a stack left in the
+    scan's ``xs`` is copied layer by layer into a fresh buffer every
+    step before the kernel reads it.  The fused FFN kernel runs once a
+    layer, so its weights leave ``xs``: the scan body closes over the
+    ``[L, …]`` stacks and hands the kernel ``(stack, layer)``
+    (:func:`_with_weight_stacks`), and the kernel reads each weight once,
+    in place.  The attention weights stay in ``xs``: the per-slot
+    ``vmap`` runs ``fused_decode`` once per slot, so each layer's
+    ``wqkv``/``wo`` are read once per slot, and the per-layer copy (which
+    XLA stages in VMEM when it fits) is the cheaper source for those
+    reads than the stack in HBM."""
+    f = blk.get("ffn")
+    if not isinstance(f, df.PackedFFNWeights):
+        return blk, {}
+    stacks = {n: getattr(f, n) for n in _FFN_STACKED}
+    return dict(blk, ffn=f._replace(**dict.fromkeys(_FFN_STACKED))), stacks
+
+
+def _with_weight_stacks(blk: Dict[str, Any], stacks, gi) -> Dict[str, Any]:
+    """Layer ``gi`` of a block split by :func:`_split_weight_stacks`: its
+    FFN bundle carries the whole stacks and the layer to read."""
+    if not stacks:
+        return blk
+    return dict(blk, ffn=blk["ffn"]._replace(layer=gi, **stacks))
+
+
 def decode_step(ctx: ParallelCtx, cfg: ModelConfig, scfg: ServeConfig,
                 params_dm: PyTree, state: Dict[str, Any],
                 tokens: jax.Array) -> Tuple[jax.Array, Dict[str, Any]]:
@@ -619,6 +652,9 @@ def decode_step(ctx: ParallelCtx, cfg: ModelConfig, scfg: ServeConfig,
     # through scan ys (§Perf iter 3: ~3× decode HBM-byte reduction).
     n_groups_t = jnp.arange(max(n_groups, 1))
     work0 = jnp.zeros_like(cache_len)
+    split = [_split_weight_stacks(b) for b in params["blocks"]]
+    scanned = tuple(b for b, _ in split)
+    stacks = [st for _, st in split]
 
     def group_body(carry, inp):
         x, caches, work = carry
@@ -631,7 +667,7 @@ def decode_step(ctx: ParallelCtx, cfg: ModelConfig, scfg: ServeConfig,
         for p_i in range(period):
             with jax.named_scope("kv_read"):
                 cache_i = jax.tree.map(lambda l: l[gi], caches[p_i])
-            blk = blks[p_i]
+            blk = _with_weight_stacks(blks[p_i], stacks[p_i], gi)
             enc = None
             if ca is not None:
                 blk = dict(blk)
@@ -647,9 +683,9 @@ def decode_step(ctx: ParallelCtx, cfg: ModelConfig, scfg: ServeConfig,
                     caches[p_i], nc))
         return (x, tuple(new_caches), work), None
 
-    xs = ((tuple(params["blocks"]), n_groups_t, params["cross_attn"],
+    xs = ((scanned, n_groups_t, params["cross_attn"],
            enc_kv_all["k"], enc_kv_all["v"]) if cfg.encoder is not None
-          else (tuple(params["blocks"]), n_groups_t))
+          else (scanned, n_groups_t))
     with jax.named_scope("layers"):
         (x, new_caches, work), _ = lax.scan(
             group_body, (x, tuple(state["layers"]), work0), xs)

@@ -241,13 +241,18 @@ def test_prepacked_vs_adapter_parity_gqa():
 def test_engine_backend_parity_tokens():
     """Full engine: greedy tokens agree between backends (GQA with
     sliding window + softcap, and MLA), pallas in interpret mode with
-    the serve-layout prepack auto-enabled."""
+    the serve-layout prepack auto-enabled.  The GQA model has five
+    layers: two scanned groups of its two-layer pattern, whose fused FFN
+    kernel reads the ``[L, …]`` weight stacks by layer index (one
+    ``weights_by_index`` per scanned layer position), and one unstacked
+    tail layer that passes its own weights."""
     run_multidevice("""
     from repro.configs import get_config, reduced
+    from repro.core import tracecount
     from repro.launch.mesh import make_test_mesh
     from repro.launch.serve import build_engine, generate
-    for arch in ("gemma2-27b", "deepseek-v2-lite"):
-        cfg = reduced(get_config(arch))
+    for arch, n_layers in (("gemma2-27b", 5), ("deepseek-v2-lite", 0)):
+        cfg = reduced(get_config(arch), n_layers=n_layers)
         mesh = make_test_mesh()
         outs = {}
         for backend in ("xla", "pallas"):
@@ -256,6 +261,18 @@ def test_engine_backend_parity_tokens():
                 interpret=(backend == "pallas"))
             # prepack rides the auto default: on exactly for pallas
             assert scfg.prepack == (backend == "pallas"), scfg
+            if backend == "pallas" and arch == "gemma2-27b":
+                period = len(cfg.block_pattern)
+                n_tail = cfg.n_layers % period
+                assert n_tail == 1 and cfg.n_layers // period == 2, cfg
+                with tracecount.counting() as c:
+                    jax.eval_shape(dec, params["serve"], state,
+                                   jnp.zeros((4,), jnp.int32))
+                c = dict(c)
+                # two kernels per traced layer position (scanned and
+                # tail) and the head; only the scanned FFNs by index
+                assert c.get("pallas_kernel") == 2 * (period + n_tail) + 1, c
+                assert c.get("weights_by_index") == period, c
             key = jax.random.PRNGKey(0)
             prompts = jax.random.randint(key, (4, 16), 0, cfg.vocab_size)
             toks, _ = generate(cfg, params, pf, dec, state, prompts, 5,

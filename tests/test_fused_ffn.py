@@ -84,6 +84,39 @@ def test_fused_ffn_block_f_tiling_invariance():
                                    rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("gated", [True, False])
+@pytest.mark.parametrize("layer", ["first", "last"])
+@pytest.mark.parametrize("post_norm", [False, True])
+def test_fused_ffn_stacked_weights_by_layer_index(gated, layer, post_norm):
+    """``[L, …]`` weight stacks read in place at ``layer`` give exactly
+    the outputs of the same kernel on that layer's 2-D slices (the decode
+    scan's path vs one layer's weights), with and without the Gemma-2
+    post-attention norm, and the call counts one ``weights_by_index``."""
+    from repro.core import tracecount
+    from repro.kernels.fused_ffn.fused_ffn import fused_ffn_block
+    rng = np.random.default_rng(4)
+    L, B, D, F = 3, 2, 16, 32
+    i = 0 if layer == "first" else L - 1
+    x = _mk(rng, (B, D), jnp.bfloat16)
+    a = _mk(rng, (B, D), jnp.bfloat16)
+    wi = _mk(rng, (L, D, F), jnp.bfloat16, 0.05)
+    wg = _mk(rng, (L, D, F), jnp.bfloat16, 0.05) if gated else None
+    wo = _mk(rng, (L, F, D), jnp.bfloat16, 0.05)
+    ln2 = _mk(rng, (D,), jnp.float32, 0.1)
+    post1 = _mk(rng, (D,), jnp.float32, 0.1) if post_norm else None
+    kw = dict(act="silu", block_f=8, interpret=True)
+    with tracecount.counting() as c:
+        got = fused_ffn_block(x, a, wi, wg, wo, ln2, post1, jnp.float32(1.0),
+                              layer=jnp.int32(i), **kw)
+        n_stacked = c["weights_by_index"]
+        want = fused_ffn_block(x, a, wi[i], None if wg is None else wg[i],
+                               wo[i], ln2, post1, jnp.float32(1.0), **kw)
+        assert c["weights_by_index"] == n_stacked == 1, dict(c)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32))
+
+
 # ---------------------------------------------------------------------------
 # Fused tail vs the unfused layer composition (single device)
 # ---------------------------------------------------------------------------

@@ -138,6 +138,17 @@ def test_prepack_mla_fold_matches_manual():
 # ---------------------------------------------------------------------------
 @pytest.mark.multidevice
 def test_counters_dataflow_packed_vs_adapter():
+    """One prepacked attention layer traces one kernel, one fused
+    ClusterReduce and no weight gather or ``weight_slice``; the adapter
+    path pays both.
+
+    ``weight_slice`` counts the per-layer rank slicing of the train-layout
+    adapters, which prepacking removed; it reads 0 on the prepacked path
+    whatever the decode scan does with the stacked weights.  The scan's
+    own per-layer weight copies are a different thing: the FFN's went
+    when the fused FFN kernel began to read the ``[L, …]`` stacks by
+    layer index, which ``weights_by_index`` counts
+    (tests/test_backend_parity.py)."""
     run_multidevice("""
     from repro.core import dataflow as df
     from repro.core import primitives as prim

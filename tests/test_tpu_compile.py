@@ -70,20 +70,24 @@ def compile_for(one_chip):
     jax.config.update("jax_enable_compilation_cache", was)
 
 
-def _assert_kernel(compiled, name):
-    """The compiled module runs the Pallas kernel as a TPU custom call
-    that carries the kernel's name: as the instruction's own name
-    (``%<name>.<n> = ...``), or, where ``vmap`` turned the per-slot
-    kernel into a loop of calls, in its ``op_name`` metadata
-    (``.../vmap(<name>)/while/body/closed_call``)."""
+def _assert_kernel(compiled, *names):
+    """The compiled module runs the Pallas kernels as TPU custom calls,
+    each carrying one of the kernels' ``names`` and each name carried by
+    some call: as the instruction's own name (``%<name>.<n> = ...``), or,
+    where ``vmap`` turned the per-slot kernel into a loop of calls, in
+    its ``op_name`` metadata (``.../vmap(<name>)/while/body/closed_call``)."""
     calls = [ln for ln in compiled.as_text().splitlines()
              if 'custom_call_target="tpu_custom_call"' in ln]
     assert calls
+    found = set()
     for ln in calls:
         inst = re.match(r"\s*(?:ROOT )?%([\w\-]+?)(?:\.\d+)? = ", ln)
         path = re.search(r'op_name="([^"]*)"', ln).group(1).split("/")
-        assert (inst.group(1) == name or name in path
-                or f"vmap({name})" in path), (inst.group(0), path)
+        carried = [n for n in names if inst.group(1) == n or n in path
+                   or f"vmap({n})" in path]
+        assert carried, (inst.group(0), path, names)
+        found.update(carried)
+    assert found == set(names), (found, names)
 
 
 @pytest.mark.parametrize("mode", ["partial_o", "partial_o_ring", "adapter"])
@@ -129,6 +133,94 @@ def test_fused_ffn_compiles_granite(compile_for):
                     ((D, F), BF16), ((D, F), BF16), ((F, D), BF16),
                     ((D,), BF16))
     _assert_kernel(c, "fused_ffn")
+
+
+def _decode_step_3_layers(compile_for, topo, arch):
+    """AOT-compile ``decode_step`` as ``launch/serve.build_engine_full``
+    wraps it (prepacked Pallas path, fused head, ``SLOTS`` slots), for
+    ``arch`` at its published widths cut to 3 layers, on one described
+    chip.  Returns ``(cfg, compiled)``."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from repro.configs import depth_cut
+    from repro.configs.base import ShapeConfig
+    from repro.core.autotune import tune_serving
+    from repro.launch.mesh import device_mesh, dp_axes_of
+    from repro.launch.specs import (_unwrap2, _wrap2, ctx_for,
+                                    serving_layout, state_spec_tree)
+    from repro.models.transformer import init_device_major, param_specs
+    from repro.serving.engine import (ServeConfig, decode_step,
+                                      init_decode_state)
+    from repro.serving.prepack import (attn_subtree, bundle_ffn,
+                                       bundle_head, merge_packed,
+                                       prepack_for_serving)
+
+    cfg = depth_cut(get_config(arch), 3)
+    mesh = device_mesh(topo.devices[:1])
+    lay = serving_layout(cfg, ShapeConfig("serve", MAX_SEQ, SLOTS, "decode"),
+                         1)
+    ctx = ctx_for(mesh, lay, fused_combine=False)
+    plan = tune_serving(cfg, seq_len=MAX_SEQ, batch=SLOTS, model_axis=1,
+                        backend="pallas", prepack="on", table_path=None)
+    scfg = ServeConfig(max_seq=MAX_SEQ, batch_local=SLOTS, backend="pallas",
+                       block_s=plan.block_s, block_f=plan.block_f,
+                       block_v=plan.block_v, prepack=True)
+    p_abs = jax.eval_shape(
+        lambda: init_device_major(cfg, lay, jax.random.PRNGKey(0)))
+    pack = lambda t: prepack_for_serving(cfg, lay, t, backend="pallas")
+    a_abs = jax.eval_shape(pack, attn_subtree(p_abs))
+    bundle = lambda t: bundle_head(cfg, bundle_ffn(cfg, t, backend="pallas"),
+                                   backend="pallas")
+    sv_abs = bundle(merge_packed(p_abs, a_abs))
+    sv_specs = bundle(merge_packed(param_specs(cfg, p_abs),
+                                   param_specs(cfg, a_abs)))
+    st_abs = jax.tree.map(
+        lambda l: jax.ShapeDtypeStruct((1, 1) + l.shape, l.dtype),
+        jax.eval_shape(lambda: init_decode_state(cfg, scfg, ctx)))
+    dp = dp_axes_of(mesh)
+    st_specs = state_spec_tree(st_abs, dp)
+
+    def body(params, state, tokens):
+        nxt, new = decode_step(ctx, cfg, scfg, params, _unwrap2(state),
+                               tokens)
+        return nxt, _wrap2(new)
+
+    dec = shard_map(body, mesh=mesh, in_specs=(sv_specs, st_specs, P(dp)),
+                    out_specs=(P(dp), st_specs), check_vma=False)
+    leaves, tree = jax.tree.flatten(
+        (sv_abs, st_abs, jax.ShapeDtypeStruct((SLOTS,), jnp.int32)))
+    c = compile_for(lambda *xs: dec(*jax.tree.unflatten(tree, xs)),
+                    *[(l.shape, l.dtype) for l in leaves])
+    return cfg, c
+
+
+@pytest.mark.parametrize("arch", ["granite-8b", "minitron-4b"])
+def test_decode_scan_reads_weight_stacks_granite(compile_for, topo, arch):
+    """The real prepacked decode step, 3 layers at published widths (the
+    gated granite FFN and the non-gated minitron one): the fused FFN
+    kernel reads the ``[L, …]`` weight stacks by layer index, so the
+    compiled module writes no buffer of one layer's FFN weights (a
+    ``dynamic-slice`` fusion per weight and layer before) and copies no
+    stack: a stack-shaped value is only the parameter itself, a bitcast
+    or a loop-tuple element of it.  The temp bytes stay below one FFN
+    weight matrix (236 MB before, at granite widths).  ``fused_decode``,
+    ``fused_ffn`` and ``fused_head`` keep their names: a trace reads
+    per-kernel time by them."""
+    cfg, c = _decode_step_3_layers(compile_for, topo, arch)
+    L, D, F = cfg.n_layers, cfg.d_model, cfg.d_ff
+    shape = lambda *s: f"bf16[{','.join(map(str, s))}]"
+    layer, stack = {shape(D, F), shape(F, D)}, {shape(L, D, F), shape(L, F, D)}
+    aliases = ("parameter(", "get-tuple-element(", "bitcast(")
+    writes = [ln.strip()[:160] for ln in c.as_text().splitlines()
+              if (m := re.match(r"\s*(?:ROOT )?%\S+ = (\w+\[[\d,]*\])\S* (.*)",
+                                ln))
+              and (m.group(1) in layer
+                   or (m.group(1) in stack
+                       and not m.group(2).startswith(aliases)))]
+    assert not writes, writes
+    assert c.memory_analysis().temp_size_in_bytes < D * F * 2
+    _assert_kernel(c, "fused_decode", "fused_ffn", "fused_head")
 
 
 def test_fused_head_compiles_granite(compile_for):
